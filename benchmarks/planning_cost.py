@@ -18,7 +18,7 @@ from repro.core.mdm import plan_layer
 from repro.core.tiling import CrossbarSpec
 from repro.deploy import plan_matrices
 from repro.kernels.manhattan_score import manhattan_score
-from repro.kernels.runtime import INTERPRET
+from repro.kernels.runtime import default_interpret
 
 
 def _time(fn, *args, reps: int = 3) -> float:
@@ -63,9 +63,10 @@ def run(verbose: bool = True) -> dict:
     masks = (jax.random.uniform(jax.random.fold_in(key, 0),
                                 (256, 64, 64)) < 0.2).astype(jnp.uint8)
     dt = _time(lambda m: manhattan_score(m, nf_unit=spec.nf_unit), masks)
-    out["score_kernel_256tiles"] = {"seconds": dt, "interpret": INTERPRET}
+    interpret = default_interpret()
+    out["score_kernel_256tiles"] = {"seconds": dt, "interpret": interpret}
     if verbose:
-        label = "interpret" if INTERPRET else "compiled"
+        label = "interpret" if interpret else "compiled"
         print(f"  manhattan_score kernel (256 tiles, {label}): "
               f"{dt*1e3:.1f} ms")
     return out

@@ -27,6 +27,7 @@ from typing import Callable
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro import telemetry as tm  # noqa: E402  (stdlib-only, jax-free)
+from repro.cachedirs import setup_compile_cache  # noqa: E402  (jax-free at import)
 
 # 8-way host-device simulation for the sharded-solver rows (must land
 # before the first jax import initialises the backend); append so an
@@ -167,6 +168,7 @@ def resolve_only(token: str) -> list[Bench]:
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="reduced tile counts / training steps")

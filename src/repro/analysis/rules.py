@@ -33,7 +33,6 @@ _COMPAT_WRAPPED = {
 _PROBE_TARGETS = {
     "jax.lax.linalg.tridiagonal_solve":
         "repro.compat.has_batched_tridiagonal_solve",
-    "pallas_call": "repro.compat.has_pallas_lowering",
 }
 
 

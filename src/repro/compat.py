@@ -1,32 +1,25 @@
-"""Version-compatibility shims for the JAX APIs this repo depends on.
+"""Single entry points for the version-sensitive JAX APIs this repo uses.
 
-Two JAX API moves bit this codebase (both are handled here so call sites
-stay version-agnostic):
+Each shim has one body, written for the installed JAX (0.9.0).  Call
+sites go through these names (reprolint RPL001 enforces it), so the next
+JAX move is absorbed here and nowhere else:
 
-* ``jax.enable_x64`` — removed as a public context manager; the
-  supported spelling is ``jax.experimental.enable_x64()`` (a
-  config-scoped context manager that affects *tracing*, so wrap the
-  jit'd call site, not the kernel body).  Use :func:`enable_x64`.
-* ``jax.sharding.AbstractMesh`` — since JAX 0.4.35 the constructor
-  takes a single ``shape_tuple`` of ``(name, size)`` pairs instead of
-  the older ``(axis_sizes, axis_names)`` pair of tuples.  Use
-  :func:`make_abstract_mesh` with the old-style arguments.
-* ``jax.shard_map`` — newer JAX exposes it at top level with a
-  ``check_vma`` kwarg; 0.4.x has ``jax.experimental.shard_map`` with
-  ``check_rep``.  Use :func:`shard_map` (``check_vma`` spelling).
+* :func:`enable_x64` — the config-scoped float64 context manager
+  (``jax.enable_x64``).  It affects *tracing*, so wrap the jit'd call
+  site, not the kernel body.
+* :func:`make_abstract_mesh` — ``jax.sharding.AbstractMesh`` from
+  ``(axis_sizes, axis_names)``.
+* :func:`shard_map` — ``jax.shard_map`` with its ``check_vma`` and
+  ``axis_names`` keywords.
 
-Backend capability probes also live here:
+One backend capability probe also lives here:
 
 * :func:`has_batched_tridiagonal_solve` — whether
   ``jax.lax.linalg.tridiagonal_solve`` lowers (and executes) with
   leading batch dimensions on the active backend.  The batched crossbar
   engine's line preconditioner depends on it; backends without the
-  batched lowering fall back to the Jacobi diagonal.
-* :func:`has_pallas_lowering` — whether ``pallas_call`` compiles and
-  runs natively (non-interpret) on the active backend.  The CIM matmul
-  dispatch (``repro.kernels.cim_mvm.ops.cim_mvm``) uses it to pick the
-  Pallas kernel where it lowers and the fused XLA fallback everywhere
-  else, so interpret mode never lands on a hot path.
+  batched lowering fall back to the Jacobi diagonal, and the engine
+  reports which one it picked (``repro.crossbar.batched``).
 """
 from __future__ import annotations
 
@@ -37,57 +30,34 @@ import jax
 
 
 def enable_x64(enabled: bool = True) -> ContextManager:
-    """Config-scoped float64 enablement across JAX versions.
+    """Config-scoped float64 enablement.
 
     Prefer wrapping the *outermost* (trace-time) call: inside an already
     traced jit the dtypes are frozen and the flag has no effect.
     """
-    try:  # JAX >= 0.4.x: the supported public location
-        from jax.experimental import enable_x64 as _enable_x64
-        return _enable_x64(enabled)
-    except ImportError:  # pragma: no cover - very old JAX
-        return jax.enable_x64(enabled)  # type: ignore[attr-defined]
+    return jax.enable_x64(enabled)
 
 
 def make_abstract_mesh(axis_sizes: Sequence[int],
                        axis_names: Sequence[str]):
-    """Build an ``AbstractMesh`` from old-style (sizes, names) arguments.
-
-    JAX >= 0.4.35 wants ``AbstractMesh((("data", 16), ("model", 16)))``;
-    earlier releases wanted ``AbstractMesh((16, 16), ("data", "model"))``.
-    """
+    """Build an ``AbstractMesh`` from ``(axis_sizes, axis_names)``."""
     from jax.sharding import AbstractMesh
 
     if len(axis_sizes) != len(axis_names):
         raise ValueError(
             f"axis_sizes {tuple(axis_sizes)} and axis_names "
             f"{tuple(axis_names)} must have equal length")
-    shape_tuple = tuple(zip(axis_names, axis_sizes))
-    try:
-        return AbstractMesh(shape_tuple)
-    except TypeError:  # pragma: no cover - pre-0.4.35 signature
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def shard_map(f: Callable, mesh: Any, in_specs: Any, out_specs: Any,
               check_vma: bool = True,
               axis_names: frozenset | None = None) -> Callable:
-    """``jax.shard_map`` across versions, with the new-style arguments.
-
-    ``check_vma`` maps onto 0.4.x's ``check_rep``; ``axis_names`` (the
-    set of *manual* mesh axes in the new API) maps onto 0.4.x's ``auto``
-    (its complement: the mesh axes left automatic).
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma, **kw)
+    """``jax.shard_map``; ``axis_names`` is the set of *manual* mesh axes
+    (None: all of them)."""
+    kw = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 @lru_cache(maxsize=None)
@@ -119,48 +89,6 @@ def has_batched_tridiagonal_solve(platform: str | None = None) -> bool:
     t.start()
     t.join()
     return bool(out and out[0])
-
-
-@lru_cache(maxsize=None)
-def has_pallas_lowering(platform: str | None = None) -> bool:
-    """Probe: does ``pallas_call`` lower natively on this backend?
-
-    Executes a trivial Pallas kernel with ``interpret=False`` on
-    ``platform`` (default: the active backend) and reports whether it
-    compiles and returns the right answer.  TPU (Mosaic) passes; CPU/GPU
-    builds without a Triton/Mosaic-GPU lowering raise at compile time
-    and report False, routing callers to their fused XLA fallbacks.
-    Runs in a worker thread for the same trace-escape reason as
-    :func:`has_batched_tridiagonal_solve`; cached per platform.
-    """
-    import threading
-
-    out: list[bool] = []
-    t = threading.Thread(target=lambda: out.append(_probe_pallas(platform)),
-                         daemon=True)
-    t.start()
-    t.join()
-    return bool(out and out[0])
-
-
-def _probe_pallas(platform: str | None) -> bool:
-    try:
-        import numpy as np
-        from jax.experimental import pallas as pl
-
-        def kernel(x_ref, o_ref):
-            o_ref[...] = x_ref[...] + 1.0
-
-        x = np.zeros((8, 128), np.float32)
-        if platform:
-            x = jax.device_put(x, jax.devices(platform)[0])
-        out = np.asarray(pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((8, 128), np.float32),
-            interpret=False)(x))
-        return bool(np.all(out == 1.0))
-    except Exception:  # no native lowering -> XLA fallback
-        return False
 
 
 def _probe_tridiagonal(platform: str | None) -> bool:

@@ -12,7 +12,6 @@ from repro.deploy.cache import (  # noqa: F401
     PLAN_CACHE_VERSION,
     CacheStats,
     PlanCache,
-    default_cache_dir,
     manifest_key,
     plan_key,
     weight_fingerprint,

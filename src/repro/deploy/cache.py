@@ -18,10 +18,8 @@ A hit is one ``read()`` plus ``np.frombuffer`` views — zip-based
 dominate a whole-model cache hit.  Loaded plans keep numpy leaves —
 consumers touch them through jnp ops, which transfer on first use — so
 a full-model cache hit does no device work at all.  The
-default root sits next to the persistent JAX compilation cache when one
-is configured (``.jax_cache/`` -> ``.mdm_plan_cache/``), mirroring how
-compile artefacts already persist across runs; otherwise it falls back
-to ``~/.cache/repro/mdm_plans``.  Writes are atomic (tmp +
+default root is the checkout's ``.mdm_plan_cache/``
+(:func:`repro.cachedirs.plan_cache_dir`).  Writes are atomic (tmp +
 ``os.replace``), so a crash mid-write never corrupts an entry.
 
 On top of the per-entry store, a **per-checkpoint manifest** packs a
@@ -40,10 +38,10 @@ import os
 import tempfile
 import threading
 
-import jax
 import numpy as np
 
 from repro import telemetry as tm
+from repro.cachedirs import plan_cache_dir
 from repro.core.mdm import MdmPlan
 from repro.core.tiling import CrossbarSpec
 
@@ -67,16 +65,6 @@ _M_READ_BYTES = tm.counter(
 # Bump when the MdmPlan layout or planning semantics change: old
 # entries become unreachable (different keys) instead of wrongly hit.
 PLAN_CACHE_VERSION = 1
-
-
-def default_cache_dir() -> str:
-    """Plan-cache root: next to the JAX compilation cache if configured."""
-    jax_dir = jax.config.jax_compilation_cache_dir
-    if jax_dir:
-        parent = os.path.dirname(os.path.abspath(jax_dir))
-        return os.path.join(parent, ".mdm_plan_cache")
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                        "mdm_plans")
 
 
 def weight_fingerprint(w) -> str:
@@ -152,7 +140,7 @@ class PlanCache:
     """
 
     def __init__(self, root: str | None = None):
-        self.root = root or default_cache_dir()
+        self.root = root or plan_cache_dir()
         self.stats = CacheStats()
         self._lock = threading.Lock()
 

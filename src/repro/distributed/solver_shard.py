@@ -141,7 +141,9 @@ def _sharded_solver(mesh: Mesh, axes: tuple[str, ...], maxiter: int,
         # for NaN, which would count a diverged tile as converged.
         unconverged = jax.lax.psum(
             jnp.sum((~tile_converged(res, tol)).astype(jnp.int32)), axes)
-        iters = jax.lax.pmax(res.iterations, axes)
+        # int32: XLA:TPU lowers only the sum all-reduce for 64-bit ints,
+        # and the x64 scope makes the loop counter int64.
+        iters = jax.lax.pmax(res.iterations.astype(jnp.int32), axes)
         return ShardedSolveResult(res.currents, res.ideal, res.nf_cols,
                                   res.nf_total, res.residual, iters,
                                   unconverged)
@@ -176,7 +178,7 @@ def _sharded_solver_g(mesh: Mesh, axes: tuple[str, ...], maxiter: int,
                             precision, chain_impl)
         unconverged = jax.lax.psum(
             jnp.sum((~tile_converged(res, tol)).astype(jnp.int32)), axes)
-        iters = jax.lax.pmax(res.iterations, axes)
+        iters = jax.lax.pmax(res.iterations.astype(jnp.int32), axes)  # see above
         return ShardedSolveResult(res.currents, res.ideal, res.nf_cols,
                                   res.nf_total, res.residual, iters,
                                   unconverged)

@@ -8,7 +8,6 @@ Each kernel lives in its own subpackage with the canonical layout:
 
 Kernels target TPU (VMEM tiling, MXU-aligned blocks); on this CPU
 container they are validated via ``interpret=True``, which executes the
-kernel body per-block in Python.  ``repro.kernels.runtime.INTERPRET``
-flips automatically based on the backend.
+kernel body per-block in Python.  The validation wrappers default to
+``repro.kernels.runtime.default_interpret()``, read at call time.
 """
-from repro.kernels.runtime import INTERPRET  # noqa: F401

@@ -3,4 +3,5 @@ from repro.kernels.cim_mvm.ops import (  # noqa: F401
     cim_mvm,
     deploy,
     resolve_impl,
+    served_impl,
 )

@@ -9,17 +9,21 @@ any activation batch.  When ``cfg.cim.enabled`` is set, the model zoo
 
 Dispatch (``impl``):
 
-* ``"auto"`` (default) — the Pallas kernel on TPU where ``pallas_call``
-  lowers natively (``repro.compat.has_pallas_lowering``; the kernel's
-  grid-accumulation pattern assumes TPU's sequential grid, see
-  :func:`resolve_impl`), the fused XLA fallback
-  (:mod:`repro.kernels.cim_mvm.xla`) everywhere else.  Interpret mode
-  is **never** selected automatically: it executes the kernel body
-  block-by-block in Python and is orders of magnitude too slow for
-  serving.
+* ``"auto"`` (default) — the Pallas kernel on TPU, the fused XLA path
+  (:mod:`repro.kernels.cim_mvm.xla`) everywhere else.  There is no
+  capability probe: on TPU a kernel that does not compile raises.  The
+  kernel's grid accumulation assumes TPU's sequential grid, so GPU stays
+  on XLA (see :func:`resolve_impl`).  Interpret mode is **never**
+  selected automatically: it executes the kernel body block-by-block in
+  Python and is orders of magnitude too slow for serving.
 * ``"pallas"`` / ``"xla"`` — force one production path.
 * ``"interpret"`` — the Pallas kernel under ``pallas_call(interpret=
   True)``; test/validation only (bit-faithful BlockSpec checking).
+
+Deployments carrying a per-weight ``gain``, a column permutation
+(``col_pos``) or per-read noise are served by the XLA path under
+``"auto"`` on every backend: the kernel has no operands for them.
+:func:`served_impl` says which path a deployment gets.
 """
 from __future__ import annotations
 
@@ -30,7 +34,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.compat import has_pallas_lowering
 from repro.core.bitslice import codes_to_bits, quantize_magnitude
 from repro.core.mdm import MdmPlan, plan_from_bits
 from repro.core.noise import PAPER_ETA
@@ -147,10 +150,15 @@ def deploy(w: jax.Array, spec: CrossbarSpec, mode="mdm",
 
 
 def _block_sizes(M: int, I: int, N: int, wpt: int) -> tuple[int, int, int]:
-    bm = 128 if M >= 128 else round_up(M, 8)
+    """(BM, BI, BN) for the Pallas grid; I and N are the padded dims.
+
+    Every block is (8, 128)-aligned or spans its whole axis, as Mosaic
+    requires.  The position block is ``BN // wpt`` lanes wide, so BN is
+    ``128 * wpt`` (or the whole width when narrower).
+    """
+    bm = min(round_up(M, 8), 256)
     bi = 256 if I >= 256 else round_up(I, 8)
-    n_unit = math.lcm(wpt, 8)
-    bn = 128 if N >= 128 and 128 % n_unit == 0 else round_up(min(N, 128), n_unit)
+    bn = min(N, 128 * wpt)
     return bm, bi, bn
 
 
@@ -158,35 +166,56 @@ def resolve_impl(impl: str = "auto") -> str:
     """Resolve ``"auto"`` to the production path for the active backend.
 
     Never returns ``"interpret"`` — interpret mode must be requested
-    explicitly (tests/validation only).  The Pallas path is gated on
-    the TPU backend *and* the lowering probe: the kernel accumulates
-    its output block across sequential grid steps (`out_ref[...] +=`
-    with init at ki == 0), which is TPU grid semantics — on a GPU
-    build where pallas_call happens to lower, parallel grid cells
-    would race on that accumulator, so GPU stays on the fused XLA
-    fallback until a revisiting-safe variant exists.
+    explicitly (tests/validation only).  ``"auto"`` is the Pallas kernel
+    on TPU and the fused XLA path elsewhere: the kernel accumulates its
+    output block across sequential grid steps (`out_ref[...] +=` with
+    init at ki == 0), which is TPU grid semantics — on a GPU, parallel
+    grid cells would race on that accumulator.  The backend is read at
+    call time, so importing this module touches no device.
     """
     if impl not in IMPLS:
         raise ValueError(f"impl={impl!r} not in {IMPLS}")
     if impl == "auto":
-        if jax.default_backend() == "tpu" and has_pallas_lowering():
-            return "pallas"
-        return "xla"
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
     return impl
 
 
-@partial(jax.jit, static_argnames=("impl", "blocks"))
+def served_impl(dep: CimDeployment, impl: str = "auto",
+                noisy: bool = False) -> str:
+    """The path :func:`cim_mvm` takes for ``dep`` under ``impl``.
+
+    ``noisy`` says whether the call carries per-read noise (a read key
+    and a deployment with ``sigma_read > 0`` and a ``noise_tag``).
+    Per-weight gain, per-tile column permutations and per-read noise
+    live in the fused XLA expansion only, so ``"auto"`` sends such
+    deployments there; an explicit ``"pallas"``/``"interpret"`` request
+    for one raises instead of being rerouted (a TPU parity check would
+    otherwise attribute XLA numbers to the kernel).
+    """
+    resolved = resolve_impl(impl)
+    if resolved == "xla" or (dep.gain is None and dep.col_pos is None
+                             and not noisy):
+        return resolved
+    if impl != "auto":
+        what = ("a deployment gain" if dep.gain is not None
+                else "a column-permuted deployment"
+                if dep.col_pos is not None else "per-read noise")
+        raise ValueError(
+            f"impl={impl!r} cannot apply {what}; "
+            "use impl='xla' (or 'auto') for such deployments")
+    return "xla"
+
+
+@partial(jax.jit, static_argnames=("impl",))
 def cim_mvm(x: jax.Array, dep: CimDeployment,
-            read_key: jax.Array | None = None, impl: str = "auto",
-            blocks: tuple[int, int, int] | None = None) -> jax.Array:
+            read_key: jax.Array | None = None,
+            impl: str = "auto") -> jax.Array:
     """y = x @ W_effective for a CIM-deployed weight matrix.
 
     x: (..., in_dim); returns (..., out_dim) f32.  ``impl`` picks the
-    execution path (see module docstring); the default dispatches to the
-    Pallas kernel or the fused XLA fallback, never to interpret mode.
-    ``blocks`` tunes the Pallas/interpret grid only — the XLA fallback
-    is a single fused program with no block structure to tune, so the
-    argument has no effect there.
+    execution path (see module docstring and :func:`served_impl`); the
+    default dispatches to the Pallas kernel or the fused XLA path, never
+    to interpret mode.
 
     ``read_key`` enables per-read conductance noise: when the
     deployment carries ``sigma_read > 0`` and a ``noise_tag``, the tag
@@ -194,28 +223,9 @@ def cim_mvm(x: jax.Array, dep: CimDeployment,
     *this* read (decode steps pass a fresh key per step).  ``None``
     (the default) is bit-identical to the noiseless path.
     """
-    requested = impl
-    impl = resolve_impl(impl)
     noisy = (read_key is not None and dep.sigma_read > 0.0
              and dep.noise_tag is not None)
-    if (dep.gain is not None or dep.col_pos is not None or noisy) \
-            and impl != "xla":
-        # Per-weight nonideality gain, per-tile column permutations and
-        # per-read noise live in the fused XLA expansion only; the
-        # Pallas kernel has none of these operands.  "auto" on TPU
-        # legitimately lands here — degrade to the XLA path rather than
-        # silently dropping the injected variation / bitline remap /
-        # read noise.  An *explicit* pallas/interpret request must not
-        # be silently rerouted (a TPU parity check would attribute XLA
-        # numbers to the kernel), so surface the conflict instead.
-        if requested != "auto":
-            what = ("a deployment gain" if dep.gain is not None
-                    else "a column-permuted deployment"
-                    if dep.col_pos is not None else "per-read noise")
-            raise ValueError(
-                f"impl={requested!r} cannot apply {what}; "
-                "use impl='xla' (or 'auto') for such deployments")
-        impl = "xla"
+    impl = served_impl(dep, impl, noisy)
     batch_shape = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
     M, I = x2.shape
@@ -235,14 +245,14 @@ def cim_mvm(x: jax.Array, dep: CimDeployment,
                         read_key=rk, sigma_read=dep.sigma_read)
         return y[:, :dep.out_dim].reshape(*batch_shape, dep.out_dim)
 
-    bm, bi, bn = blocks or _block_sizes(M, i_pad, n_pad, dep.wpt)
+    bm, bi, bn = _block_sizes(M, i_pad, n_pad, dep.wpt)
     mp, ip, np_ = round_up(M, bm), round_up(i_pad, bi), round_up(n_pad, bn)
     x2 = jnp.pad(x2, ((0, mp - M), (0, ip - I)))
     codes = jnp.pad(dep.codes, ((0, ip - i_pad), (0, np_ - n_pad)))
     pos = jnp.pad(dep.pos, ((0, ip - i_pad), (0, (np_ - n_pad) // dep.wpt)))
 
     y = cim_mvm_pallas(
-        x2, codes, pos, dep.scale.reshape(1, 1),
+        x2, codes, pos, jnp.asarray(dep.scale, jnp.float32).reshape(1, 1),
         n_bits=dep.n_bits, wpt=dep.wpt, cols=dep.cols, eta=dep.eta,
         reversed_df=dep.reversed_df, block_m=bm, block_n=bn, block_i=bi,
         interpret=impl == "interpret")

@@ -1,4 +1,4 @@
-"""Fused XLA fallback for the CIM matmul (production non-TPU path).
+"""Fused XLA path for the CIM matmul (production path off TPU).
 
 Computes the same PR-distorted matmul as the Pallas kernel
 (:mod:`repro.kernels.cim_mvm.kernel`) as a single fusible XLA graph:
@@ -10,10 +10,11 @@ pipeline feeding the matmul, keeping weight traffic at 2 B/weight
 (measured against the paper's materialised-bit-plane flow in
 ``benchmarks/cim_traffic.py``).
 
-This is the hot path on every backend where ``pallas_call`` has no
-native lowering (``repro.compat.has_pallas_lowering``); interpret mode
-is strictly a test/validation vehicle and is never dispatched from
-serving code.
+This is the hot path on every backend but TPU, and on TPU for
+deployments with a per-weight gain, a column permutation or per-read
+noise (``repro.kernels.cim_mvm.ops.served_impl``); interpret mode is
+strictly a test/validation vehicle and is never dispatched from serving
+code.
 """
 from __future__ import annotations
 

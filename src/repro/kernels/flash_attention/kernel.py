@@ -12,10 +12,15 @@ train cells.  This kernel keeps everything tile-resident in VMEM:
     head index by the group size, so each KV head's tile is fetched for
     its G query heads directly from the (B*Hkv, S, Dh) layout.
   * positions-based masking (causal + sliding window + ring-buffer
-    validity) identical to the pure-JAX path.
+    validity) identical to the pure-JAX path.  Query positions arrive
+    as a (Sq, 1) column and key positions as a (1, Skv) row, so each
+    position block is 2-D and the mask is a plain broadcast compare; the
+    softmax carries are (BQ, 1) columns.
 
-MXU alignment: BQ/BK multiples of 128, Dh is the lane dim.  Validated
-in interpret mode against the pure-jnp oracle (tests/test_kernels.py).
+MXU alignment: BQ/BK multiples of 128 (or the whole padded length), Dh
+is the lane dim.  The KV axis is "arbitrary" and the others
+"parallel".  Validated in interpret mode against the pure-jnp oracle
+(tests/test_kernels_perf.py).
 """
 from __future__ import annotations
 
@@ -46,29 +51,28 @@ def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    qp = qpos_ref[...]                                   # (BQ,)
-    kp = kpos_ref[...]                                   # (BK,)
-    valid = kp[None, :] <= qp[:, None]
+    qp = qpos_ref[...]                                   # (BQ, 1)
+    kp = kpos_ref[...]                                   # (1, BK)
+    valid = kp <= qp
     if window:
-        valid &= (qp[:, None] - kp[None, :]) < window
+        valid &= (qp - kp) < window
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev, l_prev = m_s[...], l_s[...]
-    m_cur = jnp.max(s, axis=-1)
+    m_prev, l_prev = m_s[...], l_s[...]                  # (BQ, 1)
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     m_safe = jnp.maximum(m_new, NEG_INF / 2)
-    p = jnp.exp(s - m_safe[:, None])
+    p = jnp.exp(s - m_safe)
     corr = jnp.exp(jnp.minimum(m_prev - m_safe, 0.0))
     m_s[...] = m_new
-    l_s[...] = l_prev * corr + jnp.sum(p, axis=-1)
-    acc_s[...] = acc_s[...] * corr[:, None] + jax.lax.dot_general(
+    l_s[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(ki == n_k - 1)
     def _finish():
-        o_ref[0] = (acc_s[...]
-                    / jnp.maximum(l_s[...], 1e-30)[:, None]
+        o_ref[0] = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)
                     ).astype(o_ref.dtype)
 
 
@@ -77,7 +81,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            *, scale: float, window: int, group: int,
                            block_q: int, block_k: int,
                            interpret: bool) -> jax.Array:
-    """q: (BH, Sq, Dh); k, v: (BHkv, Skv, Dh). Pre-padded to blocks."""
+    """q: (BH, Sq, Dh); k, v: (BHkv, Skv, Dh); q_positions (Sq, 1) and
+    k_positions (1, Skv) int32.  Pre-padded to blocks."""
     BH, Sq, Dh = q.shape
     Skv = k.shape[1]
     n_q, n_k = Sq // block_q, Skv // block_k
@@ -89,8 +94,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_q,), lambda bh, qi, ki: (qi,)),
-            pl.BlockSpec((block_k,), lambda bh, qi, ki: (ki,)),
+            pl.BlockSpec((block_q, 1), lambda bh, qi, ki: (qi, 0)),
+            pl.BlockSpec((1, block_k), lambda bh, qi, ki: (0, ki)),
             pl.BlockSpec((1, block_q, Dh), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, Dh),
                          lambda bh, qi, ki, g=group: (bh // g, ki, 0)),
@@ -101,9 +106,12 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                                lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, Dh), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q_positions, k_positions, q, k, v)

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-from repro.kernels.runtime import INTERPRET, round_up
+from repro.kernels.runtime import default_interpret, round_up
 
 # np, not jnp: module-level jnp would compute at import time (RPL005).
 EMPTY_POS = np.int32(2 ** 30)
@@ -27,8 +27,13 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         q_positions: jax.Array, k_positions: jax.Array,
                         window: int = 0, block_q: int = 128,
                         block_k: int = 128,
-                        interpret: bool = INTERPRET) -> jax.Array:  # reprolint: disable=RPL004 -- validation wrapper: INTERPRET is False on every backend with a native lowering; production serving dispatches via cim_mvm
-    """q: (B, Sq, H, Dh); k, v: (B, Skv, Hkv, Dh) -> (B, Sq, H, Dh)."""
+                        interpret: bool | None = None) -> jax.Array:  # reprolint: disable=RPL004 -- None resolves to default_interpret() at trace time: the compiled kernel on TPU, interpret mode (validation) elsewhere
+    """q: (B, Sq, H, Dh); k, v: (B, Skv, Hkv, Dh) -> (B, Sq, H, Dh).
+
+    Positions are 1-D: ``q_positions`` (Sq,), ``k_positions`` (Skv,).
+    """
+    if interpret is None:
+        interpret = default_interpret()
     B, Sq, H, Dh = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
@@ -37,9 +42,11 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bk = min(block_k, round_up(Skv, 8))
     sq_p, sk_p = round_up(Sq, bq), round_up(Skv, bk)
 
-    qp = jnp.pad(q_positions.astype(jnp.int32), (0, sq_p - Sq))
+    # Positions as a (Sq, 1) column and a (1, Skv) row: 2-D blocks that
+    # Mosaic can tile.
+    qp = jnp.pad(q_positions.astype(jnp.int32), (0, sq_p - Sq))[:, None]
     kp = jnp.pad(k_positions.astype(jnp.int32), (0, sk_p - Skv),
-                 constant_values=EMPTY_POS)
+                 constant_values=EMPTY_POS)[None, :]
     qt = jnp.pad(q, ((0, 0), (0, sq_p - Sq), (0, 0), (0, 0)))
     kt = jnp.pad(k, ((0, 0), (0, sk_p - Skv), (0, 0), (0, 0)))
     vt = jnp.pad(v, ((0, 0), (0, sk_p - Skv), (0, 0), (0, 0)))

@@ -7,17 +7,19 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.manhattan_score.kernel import manhattan_score_pallas
-from repro.kernels.runtime import INTERPRET, round_up
+from repro.kernels.runtime import default_interpret, round_up
 
 
 @partial(jax.jit, static_argnames=("nf_unit", "block_t", "interpret"))
 def manhattan_score(masks: jax.Array, nf_unit: float = 1.0,
-                    block_t: int = 8, interpret: bool = INTERPRET):  # reprolint: disable=RPL004 -- validation wrapper: INTERPRET is False on every backend with a native lowering; planning uses the fused XLA scorer
+                    block_t: int = 8, interpret: bool | None = None):  # reprolint: disable=RPL004 -- validation wrapper: None resolves to default_interpret() at trace time, False on TPU; planning uses the fused XLA scorer
     """Row scores, row counts and per-tile NF for tile masks.
 
     masks: (..., R, C) activity masks (any integer/float 0-1 dtype).
     Returns (scores (..., R), counts (..., R), nf (...)).
     """
+    if interpret is None:
+        interpret = default_interpret()
     batch = masks.shape[:-2]
     R, C = masks.shape[-2:]
     flat = masks.reshape(-1, R, C)

@@ -3,10 +3,16 @@ from __future__ import annotations
 
 import jax
 
-# pallas_call(interpret=True) on non-TPU backends: the kernel body runs
-# block-by-block in the Python interpreter, giving bit-faithful validation
-# of the BlockSpec tiling logic without TPU hardware.
-INTERPRET: bool = jax.default_backend() != "tpu"
+
+def default_interpret() -> bool:
+    """Whether a validation wrapper runs its Pallas kernel interpreted.
+
+    True off TPU: ``pallas_call(interpret=True)`` runs the kernel body
+    block-by-block in the Python interpreter, giving bit-faithful
+    validation of the BlockSpec tiling logic without TPU hardware.  Read
+    at call time, so importing ``repro.kernels`` initialises no backend.
+    """
+    return jax.default_backend() != "tpu"
 
 
 def round_up(x: int, m: int) -> int:

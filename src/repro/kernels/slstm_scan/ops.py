@@ -6,15 +6,17 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.runtime import INTERPRET, round_up
+from repro.kernels.runtime import default_interpret, round_up
 from repro.kernels.slstm_scan.kernel import slstm_scan_pallas
 
 
 @partial(jax.jit, static_argnames=("block_b", "chunk", "interpret"))
 def slstm_scan(gx: jax.Array, r_gates: jax.Array, h0: jax.Array,
                c0: jax.Array, block_b: int = 8, chunk: int = 128,
-               interpret: bool = INTERPRET):  # reprolint: disable=RPL004 -- validation wrapper: INTERPRET is False on every backend with a native lowering; recurrent serving stays on the XLA scan
+               interpret: bool | None = None):  # reprolint: disable=RPL004 -- validation wrapper: None resolves to default_interpret() at trace time, False on TPU; recurrent serving stays on the XLA scan
     """gx: (B, T, H, 4Dh); returns (hs (B,T,H,Dh) f32, hT, cT)."""
+    if interpret is None:
+        interpret = default_interpret()
     B, T, H, Dh4 = gx.shape
     bb = min(block_b, B)
     ch = min(chunk, T)

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import telemetry as tm
+from repro.cachedirs import setup_compile_cache
 from repro.configs import SHAPES, arch_shape_cells, get_config
 from repro.configs.base import MeshConfig, ModelConfig, ShapeConfig, TrainConfig
 from repro.distributed.sharding import ShardingCtx, logical_spec
@@ -225,6 +226,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape", choices=list(SHAPES))

@@ -11,6 +11,7 @@ import jax
 import numpy as np
 
 from repro import telemetry as tm
+from repro.cachedirs import setup_compile_cache
 from repro.configs import get_config
 from repro.data import SyntheticTokenDataset
 from repro.models.model import init_params
@@ -18,6 +19,7 @@ from repro.serve.engine import ServeEngine
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.cachedirs import setup_compile_cache
 from repro.configs import get_config
 from repro.configs.base import TrainConfig
 from repro.data import SyntheticTokenDataset
@@ -20,6 +21,7 @@ from repro.train.trainer import Trainer
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -15,12 +15,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # Persistent jit cache: tier-1 is compile-bound (a flat tail of ~200
 # small jit compiles), so cache compiled executables across pytest runs
-# in-repo (.jax_cache/, gitignored).  The min-compile-time floor is
-# dropped to 0 because the tail is exactly the sub-second compiles the
-# default threshold (1s) would refuse to cache.
+# where JAX_COMPILATION_CACHE_DIR says, else in-repo (.jax_cache/,
+# gitignored).  The min-compile-time floor is dropped to 0 because the
+# tail is exactly the sub-second compiles the default threshold (1s)
+# would refuse to cache.
 import jax  # noqa: E402  (env above must precede backend init)
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
+from repro.cachedirs import setup_compile_cache  # noqa: E402
+
+setup_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

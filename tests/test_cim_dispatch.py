@@ -6,15 +6,22 @@ Parity triangle per (mode, shape): the Pallas kernel in interpret mode
 non-TPU path), and the materialised ``noisy_magnitude`` paper path
 (``cim_mvm_ref``) must all agree to <= 1e-5 relative.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import has_pallas_lowering
 from repro.core.mdm import MODES
 from repro.core.tiling import CrossbarSpec
-from repro.kernels.cim_mvm.ops import IMPLS, cim_mvm, deploy, resolve_impl
+from repro.kernels.cim_mvm.ops import (
+    IMPLS,
+    cim_mvm,
+    deploy,
+    resolve_impl,
+    served_impl,
+)
 from repro.kernels.cim_mvm.ref import cim_mvm_ref
 
 SPEC = CrossbarSpec(rows=16, cols=16, n_bits=8)
@@ -53,13 +60,12 @@ def test_three_way_parity_odd_bits(mode):
 
 
 def test_resolve_impl_never_interprets():
-    """"auto" resolves to a production path on every backend; interpret
-    must be an explicit opt-in (tests only).  Pallas is TPU-gated: the
-    kernel's grid accumulator assumes sequential grid semantics."""
+    """"auto" resolves to a production path on every backend, with no
+    capability probe: Pallas on TPU, XLA elsewhere.  Interpret must be
+    an explicit opt-in (tests only)."""
     assert resolve_impl("auto") in ("pallas", "xla")
     assert resolve_impl("auto") != "interpret"
-    expect = ("pallas" if jax.default_backend() == "tpu"
-              and has_pallas_lowering() else "xla")
+    expect = "pallas" if jax.default_backend() == "tpu" else "xla"
     assert resolve_impl("auto") == expect
     for impl in IMPLS:
         if impl != "auto":
@@ -68,15 +74,30 @@ def test_resolve_impl_never_interprets():
         resolve_impl("mosaic")
 
 
-def test_pallas_probe_is_cached_bool():
-    a = has_pallas_lowering()
-    assert isinstance(a, bool)
-    assert has_pallas_lowering() == a
-    if jax.default_backend() == "cpu":
-        # 0.4.x CPU has no native pallas lowering; if this ever starts
-        # passing, the dispatch upgrade to "pallas" is free and this
-        # assert should be dropped.
-        assert a is False
+@pytest.mark.parametrize("field", ["gain", "col_pos", "noise"])
+def test_served_impl_routes_nonideal_to_xla(field, monkeypatch):
+    """Steered onto the TPU dispatch, an ideal deployment takes the
+    kernel; gain, column permutations and per-read noise take XLA under
+    "auto" and raise under an explicit kernel request."""
+    import repro.kernels.cim_mvm.ops as ops
+
+    real = ops.resolve_impl
+    monkeypatch.setattr(ops, "resolve_impl", lambda impl="auto": (
+        "pallas" if impl == "auto" else real(impl)))
+    w = jax.random.normal(jax.random.PRNGKey(5), (32, 8)) * 0.1
+    dep, _ = deploy(w, SPEC, "mdm")
+    assert served_impl(dep) == "pallas"
+    noisy = field == "noise"
+    if field == "gain":
+        dep = dataclasses.replace(dep, gain=jnp.ones(dep.codes.shape))
+    elif field == "col_pos":
+        dep, _ = deploy(w, SPEC, "xchangr")
+        assert dep.col_pos is not None
+    assert served_impl(dep, noisy=noisy) == "xla"
+    for impl in ("pallas", "interpret"):
+        with pytest.raises(ValueError):
+            served_impl(dep, impl, noisy=noisy)
+    assert served_impl(dep, "xla", noisy=noisy) == "xla"
 
 
 def test_xla_impl_batched_input():
@@ -100,5 +121,10 @@ def test_xla_matches_interpret_at_serving_scale():
     dep, _ = deploy(w, spec, "mdm")
     y_auto = np.asarray(cim_mvm(x, dep))          # auto -> xla on CPU
     y_int = np.asarray(cim_mvm(x, dep, impl="interpret"))
-    err = np.abs(y_auto - y_int) / np.maximum(np.abs(y_int), 1e-6)
-    assert err.max() <= 1e-5
+    # Norm-scaled: both paths sum the same f32 products in different
+    # orders, so they differ by f32 rounding, about sqrt(256) * 2^-24
+    # ~ 1e-6 of the output norm.  A per-element relative error is
+    # ill-posed here: outputs that cancel to near zero inflate it
+    # while their absolute error stays ~1e-7.
+    err = np.linalg.norm(y_auto - y_int) / np.linalg.norm(y_int)
+    assert err <= 1e-5

@@ -1,13 +1,17 @@
-"""JAX version-compat shims: x64 scoping, AbstractMesh construction,
-and backend capability probes."""
+"""JAX version-compat shims (one body each, for the installed JAX):
+x64 scoping, AbstractMesh construction, shard_map, and the batched
+tridiagonal capability probe."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.compat import (
     enable_x64,
     has_batched_tridiagonal_solve,
     make_abstract_mesh,
+    shard_map,
 )
 
 
@@ -19,6 +23,7 @@ def test_enable_x64_scopes_dtype():
 
 
 def test_make_abstract_mesh_old_style_args():
+    """(axis_sizes, axis_names) is the signature of the installed JAX."""
     mesh = make_abstract_mesh((16, 16), ("data", "model"))
     assert dict(mesh.shape) == {"data": 16, "model": 16}
     pod = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
@@ -28,6 +33,18 @@ def test_make_abstract_mesh_old_style_args():
 def test_make_abstract_mesh_rejects_mismatched_args():
     with pytest.raises(ValueError):
         make_abstract_mesh((16, 16), ("data",))
+
+
+@pytest.mark.parametrize("manual", [None, frozenset({"x"})])
+def test_shard_map_shim(manual):
+    """The shim forwards check_vma and the manual-axes set to
+    jax.shard_map: a per-shard sum plus one psum equals the total."""
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("x",))
+    f = shard_map(lambda a: jax.lax.psum(a.sum(keepdims=True), "x"),
+                  mesh, in_specs=P("x"), out_specs=P(),
+                  check_vma=False, axis_names=manual)
+    out = jax.jit(f)(jnp.arange(8.0))
+    np.testing.assert_allclose(np.asarray(out), [28.0])
 
 
 def test_tridiagonal_probe_on_active_backend():

@@ -53,6 +53,30 @@ def test_flash_kernel_matches_pure_jax_path():
                                rtol=2e-5, atol=2e-5)
 
 
+def test_serve_engine_pallas_attention_matches_jax():
+    """attn_impl="pallas" on ServeEngine (1-D positions, ring cache)
+    serves the same greedy tokens and logits as the chunked-scan path;
+    off TPU the kernel runs in interpret mode."""
+    from repro.configs.phi3_mini_38b import SMOKE
+    from repro.distributed.sharding import ShardingCtx
+    from repro.models.model import apply_model, init_params
+    from repro.serve.engine import ServeEngine
+
+    cfg = SMOKE.replace(dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
+                                 cfg.vocab_size)
+    outs, logits = [], []
+    for impl in ("jax", "pallas"):
+        c = cfg.replace(attn_impl=impl)
+        outs.append(np.asarray(
+            ServeEngine(c, params, max_seq=32).generate(prompts, 6)))
+        logits.append(np.asarray(apply_model(params, c, ShardingCtx(),
+                                             tokens=prompts)[0]))
+    np.testing.assert_array_equal(outs[0], outs[1])
+    np.testing.assert_allclose(logits[1], logits[0], rtol=2e-5, atol=2e-5)
+
+
 @pytest.mark.parametrize("b,t,h,dh,seed", [
     (1, 3, 1, 4, 0),        # minimal dims, t < chunk
     (5, 70, 4, 16, 1),      # strategy maxima, t spans many chunks

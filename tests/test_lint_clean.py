@@ -1,6 +1,7 @@
 """Tier-1 gate: the repo lints clean under its own rules.
 
-``src/`` plus the linted tool trees (benchmarks/, scripts/) must carry
+``src/`` plus the linted tool trees (benchmarks/, scripts/) and
+``chip_smoke.py`` must carry
 zero unsuppressed findings — every intentional deviation needs an
 inline justified suppression.  The budget assertion keeps the linter
 honest about its design point: a pure-AST pass that never imports jax
@@ -17,7 +18,7 @@ from repro.analysis import run_paths
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LINT_TREES = [os.path.join(ROOT, d)
-              for d in ("src", "benchmarks", "scripts")]
+              for d in ("src", "benchmarks", "scripts", "chip_smoke.py")]
 
 
 def test_repo_lints_clean():
