@@ -72,6 +72,10 @@ _H_OCCUPANCY = tm.histogram(
 _C_REDEPLOYS = tm.counter(
     "repro_serve_redeploys_total",
     "Async checkpoint redeploys installed into the serving loop.")
+_C_PREFILL_TOKENS = tm.counter(
+    "repro_serve_prefill_tokens_total",
+    "Tokens computed by admission prefills: the prompt's own, or "
+    "padding up to max_prompt.", labels=("kind",))
 
 _UNSET = object()
 
@@ -192,20 +196,22 @@ class ContinuousEngine:
         self._read_round = 0
         self._probe_base = jax.random.PRNGKey(nonideal_seed)
 
+        # The jitted functions' names are the device programs' names in
+        # a profiler trace (``jit_serve_prefill``, ``jit_serve_decode``).
         self.traces = {"prefill": 0, "decode": 0}
-        p_fn = make_slot_prefill(cfg, self.ctx)
-        d_fn = make_slot_decode(cfg, self.ctx)
+        prefill = make_slot_prefill(cfg, self.ctx)
+        decode = make_slot_decode(cfg, self.ctx)
 
-        def p_counted(*a, **kw):
+        def serve_prefill(*a, **kw):
             self.traces["prefill"] += 1
-            return p_fn(*a, **kw)
+            return prefill(*a, **kw)
 
-        def d_counted(*a, **kw):
+        def serve_decode(*a, **kw):
             self.traces["decode"] += 1
-            return d_fn(*a, **kw)
+            return decode(*a, **kw)
 
-        self._prefill = jax.jit(p_counted, donate_argnums=(1,))
-        self._decode = jax.jit(d_counted, donate_argnums=(1,))
+        self._prefill = jax.jit(serve_prefill, donate_argnums=(1,))
+        self._decode = jax.jit(serve_decode, donate_argnums=(1,))
 
         self._lock = threading.Lock()
         self._pending = None
@@ -248,7 +254,9 @@ class ContinuousEngine:
             self._install_pending()
             while self.scheduler.queue and self.pool.n_free:
                 req = self.scheduler.pop_admission()
-                with tm.span("serve/admit", rid=req.rid):
+                with tm.span("serve/admit", rid=req.rid,
+                             prompt_tokens=int(req.prompt.size),
+                             padded_to=self.max_prompt):
                     self._admit(req)
             _H_OCCUPANCY.observe(self.pool.n_live / self.capacity)
             if self.scheduler.live:
@@ -261,17 +269,23 @@ class ContinuousEngine:
         bank = self.banks[self.serving_epoch]
         slot = self.pool.acquire()
         L = int(req.prompt.size)
-        prompt = np.zeros((1, self.max_prompt), np.int32)
-        prompt[0, :L] = req.prompt
-        base = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
-        st = self.pool.fresh_seq_state()
-        tok, st = self._prefill(bank.params, st, jnp.asarray(prompt),
-                                jnp.int32(L), jnp.asarray(base),
-                                jnp.float32(req.temperature),
-                                bank.cim, self._next_read_key())
-        self.pool.join(slot, st, L)
+        _C_PREFILL_TOKENS.labels(kind="prompt").inc(L)
+        _C_PREFILL_TOKENS.labels(kind="padding").inc(self.max_prompt - L)
+        with tm.span("serve/admit_inputs"):
+            prompt = np.zeros((1, self.max_prompt), np.int32)
+            prompt[0, :L] = req.prompt
+            base = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
+            st = self.pool.fresh_seq_state()
+            args = (jnp.asarray(prompt), jnp.int32(L), jnp.asarray(base),
+                    jnp.float32(req.temperature), bank.cim,
+                    self._next_read_key())
+        with tm.span("serve/prefill_call"):
+            tok, st = self._prefill(bank.params, st, *args)
+        with tm.span("serve/join_call"):
+            self.pool.join(slot, st, L)
         self.scheduler.start(req, slot, self.serving_epoch)
-        tok0 = int(np.asarray(tok)[0])
+        with tm.span("serve/first_token_wait"):
+            tok0 = int(np.asarray(tok)[0])
         self._tok[slot] = tok0
         self._keys[slot] = base
         self._nem[slot] = 1
@@ -281,7 +295,8 @@ class ContinuousEngine:
 
     def _evict(self, slot: int) -> None:
         self.scheduler.finish(slot)
-        self.pool.evict(slot)
+        with tm.span("serve/evict_call"):
+            self.pool.evict(slot)
         self._tok[slot] = 0
         self._keys[slot] = 0
         self._nem[slot] = 0
@@ -298,15 +313,16 @@ class ContinuousEngine:
             tok_host = self._decode_all_banks()
         if t_on:
             _H_DECODE.observe(tm.monotonic() - t0)
-        finished = []
-        for slot in sorted(live):
-            t = int(tok_host[slot])
-            self._tok[slot] = t
-            self._nem[slot] += 1
-            if self.scheduler.record_token(slot, t):
-                finished.append(slot)
-        for slot in finished:
-            self._evict(slot)
+        with tm.span("serve/stream"):
+            finished = []
+            for slot in sorted(live):
+                t = int(tok_host[slot])
+                self._tok[slot] = t
+                self._nem[slot] += 1
+                if self.scheduler.record_token(slot, t):
+                    finished.append(slot)
+            for slot in finished:
+                self._evict(slot)
 
     def _decode_all_banks(self) -> np.ndarray:
         """One decode step for all live slots, grouped by bank epoch.
@@ -318,19 +334,22 @@ class ContinuousEngine:
         mask; tokens are taken per-slot from the owning epoch's call.
         """
         epochs = self.scheduler.epochs_live()
-        tok = jnp.asarray(self._tok)
-        keys = jnp.asarray(self._keys)
-        nem = jnp.asarray(self._nem)
-        temps = jnp.asarray(self._temp)
-        rk = self._next_read_key()
+        with tm.span("serve/decode_inputs"):
+            tok = jnp.asarray(self._tok)
+            keys = jnp.asarray(self._keys)
+            nem = jnp.asarray(self._nem)
+            temps = jnp.asarray(self._temp)
+            rk = self._next_read_key()
 
         if len(epochs) == 1:
             bank = self.banks[epochs[0]]
-            tok_out, state = self._decode(bank.params, self.pool.state,
-                                          tok, keys, nem, temps,
-                                          bank.cim, rk)
+            with tm.span("serve/decode_call"):
+                tok_out, state = self._decode(bank.params,
+                                              self.pool.state, tok, keys,
+                                              nem, temps, bank.cim, rk)
             self.pool.state = state
-            return np.asarray(tok_out)
+            with tm.span("serve/decode_wait"):
+                return np.asarray(tok_out)
 
         per_epoch_tok: dict[int, np.ndarray] = {}
         merged = None
@@ -338,10 +357,12 @@ class ContinuousEngine:
             bank = self.banks[e]
             st_in = (self.pool.fork() if i < len(epochs) - 1
                      else self.pool.state)
-            tok_out, st_out = self._decode(bank.params, st_in, tok,
-                                           keys, nem, temps, bank.cim,
-                                           rk)
-            per_epoch_tok[e] = np.asarray(tok_out)
+            with tm.span("serve/decode_call"):
+                tok_out, st_out = self._decode(bank.params, st_in, tok,
+                                               keys, nem, temps,
+                                               bank.cim, rk)
+            with tm.span("serve/decode_wait"):
+                per_epoch_tok[e] = np.asarray(tok_out)
             if merged is None:
                 merged = st_out
             else:

@@ -72,7 +72,9 @@ class SlotPool:
         # max_seq)" guarantee.
         self.traces = {"join": 0, "evict": 0, "merge": 0}
 
-        def join_fn(pool, src, slot, length):
+        # The jitted functions' names are the device programs' names in
+        # a profiler trace (``jit_serve_join`` and so on).
+        def serve_join(pool, src, slot, length):
             self.traces["join"] += 1
             return _map_slot_state(
                 pool, src,
@@ -81,7 +83,7 @@ class SlotPool:
                 kpos_fn=lambda p, s: p.at[:, slot].set(
                     jnp.where(s[:, 0] >= length, EMPTY_POS, s[:, 0])))
 
-        def evict_fn(pool, slot):
+        def serve_evict(pool, slot):
             self.traces["evict"] += 1
             return _map_slot_state(
                 pool, None,
@@ -89,7 +91,7 @@ class SlotPool:
                 pos_fn=lambda p, s: p.at[slot].set(0),
                 kpos_fn=lambda p, s: p.at[:, slot].set(EMPTY_POS))
 
-        def merge_fn(a, b, take_b):
+        def serve_merge(a, b, take_b):
             self.traces["merge"] += 1
 
             def pick(x, y):
@@ -102,11 +104,11 @@ class SlotPool:
 
         # Only the pool state donates: the B=1 source is *read* (sliced
         # into the scatter), so its buffers can't alias the output.
-        self._join = jax.jit(join_fn, donate_argnums=(0,))
-        self._evict = jax.jit(evict_fn, donate_argnums=(0,))
+        self._join = jax.jit(serve_join, donate_argnums=(0,))
+        self._evict = jax.jit(serve_evict, donate_argnums=(0,))
         # merge: jnp.where can't alias every operand pair, so donation
         # would only warn; the copy is transient (multi-epoch swaps).
-        self._merge = jax.jit(merge_fn)
+        self._merge = jax.jit(serve_merge)
 
     # -- slot bookkeeping ----------------------------------------------
 

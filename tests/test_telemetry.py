@@ -165,10 +165,14 @@ def test_disabled_records_nothing():
     assert snap["t_off_depth"]["values"][0]["value"] == 0.0
 
 
-def test_disabled_fast_path_allocates_nothing():
+@pytest.mark.parametrize("sink", [False, True])
+def test_disabled_fast_path_allocates_nothing(sink, tmp_path):
     """The off path is a flag test + return: zero allocated blocks
-    across 10k record calls (small slack for interpreter noise)."""
+    across 10k record calls and spans (small slack for interpreter
+    noise), with or without a span sink open."""
     tm.disable()
+    if sink:
+        tm.trace_to(str(tmp_path / "off.jsonl"))
     reg = MetricsRegistry()
     c = reg.counter("t_alloc_total")
     h = reg.histogram("t_alloc_seconds")
@@ -179,6 +183,8 @@ def test_disabled_fast_path_allocates_nothing():
             c.inc()
             h.observe(0.5)
             g.set(1.0)
+            with tm.span("serve/iteration", it=1):
+                pass
 
     burst(1000)  # warm method caches
     gc.collect()
@@ -186,6 +192,7 @@ def test_disabled_fast_path_allocates_nothing():
     burst(10000)
     gc.collect()
     assert sys.getallocatedblocks() - before <= 16
+    assert tm.span("x") is tm.span("y")
 
 
 def test_disabled_overhead_smoke():
@@ -248,6 +255,65 @@ def test_span_jsonl_round_trip_and_coverage(tmp_path):
     assert coverage(spans) == pytest.approx(1.0, abs=1e-6)
     assert stats["root"]["self"] == pytest.approx(
         by["root"]["dur"] - by["child/a"]["dur"] - by["child/b"]["dur"])
+
+
+def test_spans_held_in_memory_until_trace_stop(tmp_path):
+    tm.enable()
+    path = tm.trace_to(str(tmp_path / "t.jsonl"))
+    with tm.span("phase/a"):
+        pass
+    assert os.path.getsize(path) == 0       # nothing written yet
+    tm.trace_stop()
+    assert [s["name"] for s in load_spans(path)] == ["phase/a"]
+
+
+def test_replaced_sink_is_written_out(tmp_path):
+    tm.enable()
+    first = tm.trace_to(str(tmp_path / "a.jsonl"))
+    with tm.span("phase/first"):
+        pass
+    second = tm.trace_to(str(tmp_path / "b.jsonl"))
+    with tm.span("phase/second"):
+        pass
+    tm.trace_stop()
+    assert [s["name"] for s in load_spans(first)] == ["phase/first"]
+    assert [s["name"] for s in load_spans(second)] == ["phase/second"]
+    assert load_spans(second)[0]["id"] == 0
+
+
+def test_gc_pauses_recorded_as_spans(tmp_path):
+    from repro.telemetry.trace import _on_gc
+
+    tm.enable()
+    path = tm.trace_to(str(tmp_path / "t.jsonl"))
+    assert _on_gc in gc.callbacks
+    with tm.span("phase/outer"):
+        gc.collect()
+    tm.trace_stop()
+    assert _on_gc not in gc.callbacks
+    spans = load_spans(path)
+    outer = next(s for s in spans if s["name"] == "phase/outer")
+    pauses = [s for s in spans if s["name"] == "runtime/gc"
+              and s["parent"] == outer["id"]]
+    assert pauses and pauses[-1]["attrs"]["generation"] == 2
+    assert pauses[-1]["attrs"]["collected"] >= 0
+    assert pauses[-1]["depth"] == outer["depth"] + 1
+
+
+def test_spans_need_no_jax(tmp_path):
+    """repro.telemetry imports and traces in a process without jax: a
+    span then writes no profiler event and needs none."""
+    code = ("import sys; from repro import telemetry as tm; "
+            "tm.enable(); p = tm.trace_to(sys.argv[1]); "
+            "exec('with tm.span(\"phase/a\"):\\n    pass'); "
+            "tm.trace_stop(); assert 'jax' not in sys.modules; "
+            "print(open(p).read())")
+    res = subprocess.run(
+        [sys.executable, "-c", code, str(tmp_path / "t.jsonl")],
+        capture_output=True, text=True, cwd=REPO,
+        env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src")})
+    assert res.returncode == 0, res.stderr
+    assert json.loads(res.stdout)["name"] == "phase/a"
 
 
 def test_trace_report_cli(tmp_path):
@@ -419,3 +485,110 @@ def test_plan_cache_metrics_hit_and_miss(tmp_path):
     assert snap["repro_plan_cache_puts_total"]["values"][0]["value"] >= 1
     assert snap["repro_plan_cache_read_bytes_total"]["values"][0][
         "value"] > 0
+
+
+# ------------------------- continuous serving loop -------------------------
+
+ADMIT_CHILDREN = ["serve/admit_inputs", "serve/prefill_call",
+                  "serve/join_call", "serve/first_token_wait"]
+DECODE_CHILDREN = ["serve/decode_inputs", "serve/decode_call",
+                   "serve/decode_wait"]
+
+
+def _continuous(max_prompt=16):
+    from repro.configs.base import CimConfig, ModelConfig
+    from repro.models.model import init_params
+    from repro.serve import ContinuousEngine
+
+    cfg = ModelConfig(
+        name="continuous-telemetry-test", n_layers=2, d_model=32,
+        n_heads=2, n_kv_heads=2, d_ff=64, vocab_size=128,
+        block_pattern=("attn",), remat="none", dtype="float32",
+        attn_chunk=32, cim=CimConfig(enabled=False))
+    return ContinuousEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                            capacity=2, max_seq=32, max_prompt=max_prompt)
+
+
+def _serve(eng, lengths, temps=None):
+    rs = np.random.RandomState(4)
+    for i, L in enumerate(lengths):
+        eng.submit(rs.randint(0, 128, L), max_tokens=3 + i % 2,
+                   temperature=0.0 if temps is None else temps[i],
+                   seed=i)
+    return eng.run()
+
+
+def test_continuous_spans_nest_and_reach_the_profiler(tmp_path):
+    """Every new serving span is a profiler host event inside a
+    ``serve/iteration`` event, and the device programs carry the
+    ``serve_*`` names."""
+    from jax.profiler import ProfileData
+
+    eng = _continuous()
+    _serve(eng, [5])                         # compile outside the trace
+    n0 = eng.iterations
+    tm.enable()
+    path = tm.trace_to(str(tmp_path / "t.jsonl"))
+    jax.profiler.start_trace(str(tmp_path / "profile"))
+    _serve(eng, [3, 7, 16])
+    jax.profiler.stop_trace()
+    tm.trace_stop()
+
+    spans = load_spans(path)
+    by_id = {s["id"]: s for s in spans}
+    parent = {s["name"]: by_id[s["parent"]]["name"] for s in spans
+              if s["parent"] is not None and s["name"] != "runtime/gc"}
+    for name in ADMIT_CHILDREN:
+        assert parent[name] == "serve/admit", name
+    for name in DECODE_CHILDREN:
+        assert parent[name] == "serve/decode_batch", name
+    for name in ("serve/admit", "serve/decode_batch", "serve/stream"):
+        assert parent[name] == "serve/iteration", name
+    assert parent["serve/evict_call"] == "serve/stream"
+
+    (xplane,) = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+                 for f in fs if f.endswith(".xplane.pb")]
+    host = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(xplane).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+    iterations = [(a, b) for n, a, b in host if n == "serve/iteration"]
+    assert len(iterations) == eng.iterations - n0
+    inner = ADMIT_CHILDREN + DECODE_CHILDREN + [
+        "serve/admit", "serve/decode_batch", "serve/stream",
+        "serve/evict_call"]
+    events = [(n, a, b) for n, a, b in host if n in inner]
+    assert {n for n, _, _ in events} == set(inner)
+    assert len(events) == sum(1 for s in spans if s["name"] in inner)
+    for n, a, b in events:
+        assert any(lo <= a and b <= hi for lo, hi in iterations), n
+    names = {n for n, _, _ in host}
+    for fn in ("serve_prefill", "serve_decode", "serve_join",
+               "serve_evict"):
+        assert f"PjitFunction({fn})" in names, fn
+    assert eng.traces == {"prefill": 1, "decode": 1}
+
+
+def test_continuous_generation_bit_identical_on_vs_off(tmp_path):
+    lengths, temps = [3, 9, 16, 5], [0.0, 0.8, 0.0, 1.3]
+    tm.disable()
+    off = _serve(_continuous(), lengths, temps)
+    tm.enable()
+    tm.trace_to(str(tmp_path / "on.jsonl"))
+    on = _serve(_continuous(), lengths, temps)
+    assert on == off
+
+
+def test_prefill_padding_attrs_and_counter_exact(tmp_path):
+    tm.enable()
+    tm.registry().reset()
+    path = tm.trace_to(str(tmp_path / "t.jsonl"))
+    _serve(_continuous(max_prompt=16), [3, 7, 16])
+    tm.trace_stop()
+    admits = [s["attrs"] for s in load_spans(path)
+              if s["name"] == "serve/admit"]
+    assert sorted(a["prompt_tokens"] for a in admits) == [3, 7, 16]
+    assert {a["padded_to"] for a in admits} == {16}
+    vals = {v["labels"]["kind"]: v["value"] for v in tm.registry()
+            .snapshot()["repro_serve_prefill_tokens_total"]["values"]}
+    assert vals == {"prompt": 26.0, "padding": 22.0}
