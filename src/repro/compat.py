@@ -12,6 +12,10 @@ JAX move is absorbed here and nowhere else:
 * :func:`shard_map` — ``jax.shard_map`` with its ``check_vma`` and
   ``axis_names`` keywords.
 
+* :func:`uncached_compiles` — compile without writing JAX's
+  persistent compilation cache, for programs whose outputs must keep a
+  non-default layout.
+
 One backend capability probe also lives here:
 
 * :func:`has_batched_tridiagonal_solve` — whether
@@ -23,6 +27,7 @@ One backend capability probe also lives here:
 """
 from __future__ import annotations
 
+import contextlib
 from functools import lru_cache
 from typing import Any, Callable, ContextManager, Sequence
 
@@ -58,6 +63,23 @@ def shard_map(f: Callable, mesh: Any, in_specs: Any, out_specs: Any,
     kw = {} if axis_names is None else {"axis_names": axis_names}
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=check_vma, **kw)
+
+
+@contextlib.contextmanager
+def uncached_compiles():
+    """Programs compiled inside are not written to the persistent cache.
+
+    With JAX 0.9.0 on a TPU v5e, an executable read back from the
+    persistent compilation cache returns its outputs in the default
+    layout, whatever format it was compiled to give them, even outputs
+    that alias a donated input.  A program whose outputs must keep a
+    non-default format compiles in here, so that no later process reads
+    it back.  The override is thread-local.
+    """
+    from jax._src import config
+
+    with config.persistent_cache_min_compile_time_secs(float("inf")):
+        yield
 
 
 @lru_cache(maxsize=None)

@@ -1,10 +1,15 @@
-"""Chunked flash attention (pure JAX, O(seq) memory) with GQA, RoPE,
-sliding windows and ring-buffer KV-cache decode.
+"""Attention (pure JAX) with GQA, RoPE, sliding windows and ring-buffer
+KV caches.
 
-The KV sequence is scanned in fixed chunks with an online-softmax
-accumulator (running max / denominator / weighted sum), so no (Sq, Skv)
-score matrix is ever materialised — prefill at 32k and the 80-layer
-dry-runs stay linear in sequence length.  Numerics: f32 accumulation.
+:func:`flash_attention` is the prefill and training path: the KV
+sequence is scanned in fixed chunks with an online-softmax accumulator
+(running max / denominator / weighted sum), so no (Sq, Skv) score matrix
+is ever materialised — prefill at 32k and the 80-layer dry-runs stay
+linear in sequence length.  :func:`decode_attention` is the slot-pool
+decode path: one query token per lane attends to the lane's whole ring
+in one pass, reading the cache as it is stored (no chunk reshape, no
+pad), since its (B, H, C) scores are small.  Numerics: f32 softmax and
+accumulation on both paths.
 
 Masking is position-based: both query and key carry *absolute* token
 positions, so the same code path serves training (k_positions = arange),
@@ -47,6 +52,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     gqa_broadcast: str = "repeat",
                     remat_chunk: bool = False) -> jax.Array:
     """q: (B, Sq, H, Dh); k, v: (B, Skv, Hkv, Dh) -> (B, Sq, H, Dh).
+
+    The prefill and training path (and ``ServeEngine``'s decode); the
+    slot-pool decode attends through :func:`decode_attention`.
 
     Causal: key position must be <= query position (absolute positions);
     with ``window`` > 0 additionally q_pos - k_pos < window.
@@ -129,3 +137,36 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (m, l, acc), _ = jax.lax.scan(body, init, (k_chunks, v_chunks, p_chunks))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.astype(q.dtype)
+
+
+def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                     q_positions: jax.Array, k_positions: jax.Array,
+                     window: int = 0) -> jax.Array:
+    """One pass over each lane's ring: the slot-pool decode's attention.
+
+    q: (B, Sq, H, Dh) with Sq = 1 in decode; k, v: (B, C, Hkv, Dh), the
+    ring as stored; q_positions (B, Sq), k_positions (B, C) -> (B, Sq, H,
+    Dh).  The same masking as :func:`flash_attention` (``k_pos <=
+    q_pos``, the window, EMPTY_POS lanes masked out, a fully masked lane
+    reads 0) and the same f32 softmax and accumulation, but the scores
+    over the whole ring are formed at once — (B, Sq, H, C) floats, small
+    at Sq = 1 — so the cache is neither reshaped into chunks nor padded.
+    GQA groups the query heads by KV head, so the cache is never
+    broadcast to H.
+    """
+    B, Sq, H, Dh = q.shape
+    Hkv = k.shape[2]
+    G = H // Hkv
+    qf = q.astype(jnp.float32).reshape(B, Sq, Hkv, G, Dh)
+    s = jnp.einsum("bqkgd,bckd->bqkgc", qf,
+                   k.astype(jnp.float32)) * Dh ** -0.5
+    qp, kp = q_positions[:, :, None], k_positions[:, None, :]
+    valid = kp <= qp                                      # (B, Sq, C)
+    if window:
+        valid &= (qp - kp) < window
+    s = jnp.where(valid[:, :, None, None, :], s, NEG_INF)
+    m = jnp.maximum(jnp.max(s, axis=-1), NEG_INF / 2)     # fully-masked guard
+    p = jnp.exp(s - m[..., None])
+    acc = jnp.einsum("bqkgc,bckd->bqkgd", p, v.astype(jnp.float32))
+    out = acc / jnp.maximum(jnp.sum(p, axis=-1), 1e-30)[..., None]
+    return out.reshape(B, Sq, H, Dh).astype(q.dtype)

@@ -28,7 +28,12 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import ShardingCtx, logical_spec, shard
 from repro.models import schema as sch
-from repro.models.attention import EMPTY_POS, flash_attention, rope
+from repro.models.attention import (
+    EMPTY_POS,
+    decode_attention,
+    flash_attention,
+    rope,
+)
 from repro.models.moe import moe_ffn
 from repro.models.recurrent import (
     mamba_decode,
@@ -39,6 +44,8 @@ from repro.models.recurrent import (
 )
 
 ModelState = dict[str, Any]
+# Attention-cache leaves of an ``attn``/``hybrid`` slot's decode state.
+KV_KEYS = ("k", "v", "kpos")
 
 
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
@@ -97,7 +104,16 @@ def dense_mlp(p: dict, x: jax.Array, cfg: ModelConfig, ctx: ShardingCtx,
 def attn_apply(p: dict, x: jax.Array, cfg: ModelConfig, ctx: ShardingCtx,
                positions: jax.Array, cache: dict | None,
                prefix: str = "", cim: dict | None = None,
-               read_key: jax.Array | None = None):
+               read_key: jax.Array | None = None,
+               layer: jax.Array | None = None):
+    """Returns (y, new_cache).
+
+    ``layer`` is given on the slot-pool decode path only (one token,
+    per-lane positions; see :func:`apply_model`): ``cache`` then holds
+    the whole (R, B, C, ...) stack, this layer's token is written into
+    each lane's ring in place at ``[layer, lane, pos % C]``, and the
+    layer's ring is read as stored by :func:`decode_attention`.
+    """
     g = lambda n: p[prefix + n]
     c = lambda n: None if cim is None else cim.get(prefix + n)
     B, S, D = x.shape
@@ -130,10 +146,21 @@ def attn_apply(p: dict, x: jax.Array, cfg: ModelConfig, ctx: ShardingCtx,
         v = shard(v, ctx, "batch", "seq", "act_kv", "act_head_dim")
 
     new_cache = None
-    if cache is None:
+    if layer is not None:
+        C = cache["k"].shape[2]
+        idx = positions % C                            # (B, 1)
+        b = jnp.arange(B)[:, None]
+        ck = cache["k"].at[layer, b, idx].set(k.astype(cache["k"].dtype))
+        cv = cache["v"].at[layer, b, idx].set(v.astype(cache["v"].dtype))
+        cp = cache["kpos"].at[layer, b, idx].set(positions)
+        new_cache = {"k": ck, "v": cv, "kpos": cp}
+        k_all, v_all, k_pos = (
+            jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+            for a in (ck, cv, cp))
+    elif cache is None:
         k_all, v_all, k_pos = k, v, positions
     elif positions.ndim == 2:
-        # Slot-pool decode: every batch lane carries its own position
+        # Slot-pool prefill: every batch lane carries its own position
         # clock, so the cache write is a per-lane scatter and ``kpos``
         # is per-lane (B, C).  Evicted lanes hold EMPTY_POS everywhere
         # and mask themselves out of attention entirely.
@@ -170,7 +197,10 @@ def attn_apply(p: dict, x: jax.Array, cfg: ModelConfig, ctx: ShardingCtx,
         new_cache = {"k": ck, "v": cv, "kpos": cp}
         k_all, v_all, k_pos = ck, cv, cp
 
-    if cfg.attn_impl == "pallas":
+    if layer is not None:
+        out = decode_attention(q, k_all, v_all, q_positions=positions,
+                               k_positions=k_pos, window=cfg.sliding_window)
+    elif cfg.attn_impl == "pallas":
         from repro.kernels.flash_attention import flash_attention_tpu
         out = flash_attention_tpu(q, k_all, v_all, q_positions=positions,
                                   k_positions=k_pos,
@@ -195,8 +225,12 @@ def block_apply(bt: str, p: dict, x: jax.Array, cfg: ModelConfig,
                 ctx: ShardingCtx, positions: jax.Array,
                 state: dict | None, decode: bool,
                 cim: dict | None = None,
-                read_key: jax.Array | None = None):
-    """Apply one block. Returns (x, new_state_slice, aux_loss)."""
+                read_key: jax.Array | None = None,
+                layer: jax.Array | None = None):
+    """Apply one block. Returns (x, new_state_slice, aux_loss).
+
+    ``layer``: see :func:`attn_apply` (slot-pool decode only).
+    """
     aux = jnp.zeros((), jnp.float32)
     new_state: dict = {}
     h = rmsnorm(x, p["norm"], cfg.norm_eps)
@@ -204,15 +238,15 @@ def block_apply(bt: str, p: dict, x: jax.Array, cfg: ModelConfig,
     if bt == "attn":
         y, cache = attn_apply(p, h, cfg, ctx, positions,
                               None if state is None else state, cim=cim,
-                              read_key=read_key)
+                              read_key=read_key, layer=layer)
         if cache is not None:
             new_state = cache
     elif bt == "hybrid":
         cache_in = None if state is None else \
-            {k: state[k] for k in ("k", "v", "kpos")}
+            {k: state[k] for k in KV_KEYS}
         y_attn, cache = attn_apply(p, h, cfg, ctx, positions, cache_in,
                                    prefix="attn_", cim=cim,
-                                   read_key=read_key)
+                                   read_key=read_key, layer=layer)
         ssm_in = None if state is None else (state["conv"], state["ssm"])
         if decode:
             y_ssm, (cs, hs) = mamba_decode(p, h, ssm_in, prefix="ssm_")
@@ -306,35 +340,56 @@ def apply_model(params: dict, cfg: ModelConfig, ctx: ShardingCtx, *,
     pattern = cfg.block_pattern
     slot_names = [f"slot{i}_{bt}" for i, bt in enumerate(pattern)]
     xs: dict = {"params": {n: params[n] for n in slot_names}}
+    # Slot-pool decode (one token, per-lane positions): the attention
+    # caches ride the scan's carry whole, so each layer writes its B
+    # tokens in place and reads its ring once, where slicing them in
+    # as ``xs`` and back out as ``ys`` would copy the whole cache.
+    # Recurrent leaves are small and keep the xs/ys route.
+    kv: dict = {}
     if state is not None:
-        xs["state"] = {n: state[n] for n in slot_names}
+        if positions.ndim == 2 and S == 1:
+            kv = {n: {k: state[n][k] for k in KV_KEYS} for n in slot_names
+                  if "k" in state[n]}
+        if kv:
+            xs["layer"] = jnp.arange(cfg.pattern_repeats)
+        xs["state"] = {n: {k: leaf for k, leaf in state[n].items()
+                           if n not in kv or k not in KV_KEYS}
+                       for n in slot_names}
     if cim is not None:
         xs["cim"] = {n: cim.get(n, {}) for n in slot_names}
 
     train = state is None
 
     def repeat_body(carry, xs_t):
-        x, aux = carry
+        x, aux, kv = carry
+        kv = dict(kv)
         new_states = {}
         for i, bt in enumerate(pattern):
             n = slot_names[i]
             st = xs_t["state"][n] if state is not None else None
+            if n in kv:
+                st = dict(st, **kv[n])
             ci = xs_t["cim"][n] if cim is not None else None
             x, ns, a = block_apply(bt, xs_t["params"][n], x, cfg, ctx,
                                    positions, st, decode, cim=ci,
-                                   read_key=read_key)
+                                   read_key=read_key,
+                                   layer=xs_t["layer"] if kv else None)
+            if n in kv:
+                kv[n] = {k: ns[k] for k in KV_KEYS}
+                ns = {k: leaf for k, leaf in ns.items() if k not in KV_KEYS}
             new_states[n] = ns
             aux = aux + a
-        return (x, aux), new_states
+        return (x, aux, kv), new_states
 
     body = _remat_wrap(repeat_body, cfg, train)
-    (x, aux), ys = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs)
+    (x, aux, kv), ys = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), kv), xs)
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     new_state = None
     if state is not None:
-        new_state = dict(ys, pos=pos0 + S)
+        new_state = {n: dict(ys[n], **kv.get(n, {})) for n in slot_names}
+        new_state["pos"] = pos0 + S
 
     if return_hidden:
         return x, new_state, aux

@@ -26,7 +26,9 @@ bit-deterministically, new admissions see the new bank, and a bank is
 garbage-collected once nothing references it.  When live sequences
 span several epochs, each epoch decodes the full slot batch against
 its own bank and the per-slot states merge by mask — still one decode
-trace, since banks share shapes.
+trace while banks share their tree; a bank of another tree (other
+nonideal, health or mapping settings) compiles a decode of its own,
+held to the pool's layout (:meth:`repro.serve.kvcache.SlotPool.decode`).
 
 **Async redeploy.**  ``begin_redeploy(new_params)`` deploys the new
 checkpoint's tiles through the shared :class:`repro.deploy.PlanCache`
@@ -76,6 +78,9 @@ _C_PREFILL_TOKENS = tm.counter(
     "repro_serve_prefill_tokens_total",
     "Tokens computed by admission prefills: the prompt's own, or "
     "padding up to max_prompt.", labels=("kind",))
+_G_DECODE_TEMP = tm.gauge(
+    "repro_serve_decode_temp_bytes",
+    "Scratch bytes of the compiled slot-pool decode program.")
 
 _UNSET = object()
 
@@ -181,7 +186,6 @@ class ContinuousEngine:
         self._next_epoch = 1
 
         self.scheduler = RequestScheduler()
-        self.pool = SlotPool(cfg, capacity, max_seq)
         # Per-slot host mirrors of the decode operands (index-updated
         # on join/evict, like the device state).
         self._tok = np.zeros(capacity, np.int32)
@@ -211,7 +215,19 @@ class ContinuousEngine:
             return decode(*a, **kw)
 
         self._prefill = jax.jit(serve_prefill, donate_argnums=(1,))
-        self._decode = jax.jit(serve_decode, donate_argnums=(1,))
+        # The pool compiles the decode for bank 0 here, takes the layout
+        # that compile chose for the state, and runs every decode step
+        # (see ``SlotPool.decode``).
+        i32 = jax.ShapeDtypeStruct((capacity,), jnp.int32)
+        first = (params, i32,
+                 jax.ShapeDtypeStruct((capacity, 2), jnp.uint32), i32,
+                 jax.ShapeDtypeStruct((capacity,), jnp.float32), cim,
+                 self._read_base if self._read_noise else None)
+        self.pool = SlotPool(cfg, capacity, max_seq, decode=serve_decode,
+                             decode_args=first)
+        self._decode = self.pool.decode
+        self.decode_temp_bytes = self.pool.decode_temp_bytes
+        _G_DECODE_TEMP.set(self.decode_temp_bytes)
 
         self._lock = threading.Lock()
         self._pending = None
@@ -329,8 +345,8 @@ class ContinuousEngine:
 
         The common case is one epoch: a single donating decode on the
         pool state.  Across a hot swap, each live epoch decodes the
-        full batch against its own bank (the single trace serves every
-        bank — shapes match) and the per-slot states merge by epoch
+        full batch against its own bank (one trace serves every bank
+        of one tree) and the per-slot states merge by epoch
         mask; tokens are taken per-slot from the owning epoch's call.
         """
         epochs = self.scheduler.epochs_live()

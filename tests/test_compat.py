@@ -12,6 +12,7 @@ from repro.compat import (
     has_batched_tridiagonal_solve,
     make_abstract_mesh,
     shard_map,
+    uncached_compiles,
 )
 
 
@@ -73,3 +74,22 @@ def test_tridiagonal_probe_inside_trace():
 
 def test_tridiagonal_probe_unknown_platform_is_false():
     assert has_batched_tridiagonal_solve("no_such_backend") is False
+
+
+def test_uncached_compiles_writes_no_cache_entry(monkeypatch):
+    """Inside the context a fresh compile is not written to the
+    persistent cache; after it, compiles are written again."""
+    from jax._src import compilation_cache, compiler
+
+    written = []
+    monkeypatch.setattr(compiler, "_cache_read",
+                        lambda *a, **k: (None, None))   # always compile
+    monkeypatch.setattr(compilation_cache, "put_executable_and_time",
+                        lambda key, name, *a, **k: written.append(name))
+    x = np.arange(5.0, dtype=np.float32)
+    with uncached_compiles():
+        jax.jit(lambda v: v * 3.0 - 11.0)(x)
+    inside = list(written)
+    jax.jit(lambda v: v * 5.0 - 13.0)(x)
+    assert inside == []
+    assert written, "the compile after the context was not written"

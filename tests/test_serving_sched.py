@@ -277,6 +277,116 @@ def test_hot_swap_mid_load_atomicity(swap):
             assert post == fresh.run()[rid_f]
 
 
+@pytest.mark.parametrize("to", ["nonideal", "ideal"])
+def test_redeploy_to_another_tree_keeps_the_pool_layout(to):
+    """A redeploy whose deployment tree differs from the serving bank's
+    (a nonideal model folds a per-weight ``gain`` into every deployment;
+    going back to ideal devices drops it) gets a decode of its own, held
+    to the pool's format: no step raises, the pool keeps its format,
+    in-flight sequences (decoded beside a post-swap admission, on a
+    forked pool) finish bit-identical to a swap-free twin, and the
+    admission matches a fresh engine deployed directly on the new
+    bank."""
+    from repro.deploy import PlanCache
+
+    cfg = _cfg(cim=True)
+    params, params2 = _params(cfg), _params(cfg, seed=1)
+    model = NonidealModel(drift_nu=0.05, sigma_program=0.02)
+    old, new = (None, model) if to == "nonideal" else (model, None)
+    prompts = _prompts(3, seed=17)
+
+    with tempfile.TemporaryDirectory() as tmp:
+        def engine(p, nonideal):
+            return ContinuousEngine(cfg, p, capacity=3, max_seq=64,
+                                    max_prompt=16,
+                                    plan_cache=PlanCache(tmp),
+                                    nonideal=nonideal)
+
+        def fly(eng):
+            rids = [eng.submit(prompts[i], max_tokens=8,
+                               temperature=0.5 * i, seed=60 + i)
+                    for i in range(2)]
+            eng.step()                       # both in flight, epoch 0
+            return rids
+
+        ref = engine(params, old)
+        ref_out = [ref.run()[r] for r in fly(ref)]
+
+        eng = engine(params, old)
+        fmt = eng.pool.state_format
+        rids = fly(eng)
+        eng.begin_redeploy(params2, nonideal=new).join()
+        rid = eng.submit(prompts[2], max_tokens=6, temperature=0.7, seed=99)
+        eng.step()                 # installs epoch 1, admits onto it
+        assert len(eng.scheduler.epochs_live()) == 2
+        out = eng.run()
+        assert [out[r] for r in rids] == ref_out
+        assert eng.traces["decode"] == 2     # one program per tree
+        assert eng.pool.state_format is fmt
+        assert eng.pool.traces["merge"] == 1
+
+        fresh = engine(params2, new)
+        rid_f = fresh.submit(prompts[2], max_tokens=6, temperature=0.7,
+                             seed=99)
+        assert out[rid] == fresh.run()[rid_f]
+
+
+@pytest.mark.parametrize("window", [0, 16])
+def test_in_place_decode_matches_full_forward_under_churn(window):
+    """The slot-pool decode writes each token into its lane's ring in
+    place and attends in one pass; its greedy tokens equal a cache-free
+    full-sequence forward of each request, teacher-forced, under churn:
+    joins into freed slots, dead lanes, a ring that wraps
+    (``sliding_window`` 16 against ``max_seq`` 64: positions reach 31),
+    and a multi-bank swap (requests pinned to two bank epochs, decoded
+    on a forked pool and merged)."""
+    from repro.deploy import PlanCache
+    from repro.distributed.sharding import ShardingCtx
+    from repro.models.model import apply_model
+
+    cfg = _cfg(cim=True).replace(sliding_window=window)
+    params, params2 = _params(cfg), _params(cfg, seed=1)
+    rs = np.random.RandomState(21)
+    lens, budgets = [3, 8, 5, 7, 4, 8, 6], [24, 5, 12, 9, 24, 3, 17]
+    prompts = [rs.randint(0, VOCAB, size=n).astype(np.int32) for n in lens]
+    with tempfile.TemporaryDirectory() as tmp:
+        eng = ContinuousEngine(cfg, params, capacity=3, max_seq=64,
+                               max_prompt=8, plan_cache=PlanCache(tmp))
+        banks = {0: (params, eng.banks[0].cim)}
+        first = [eng.submit(prompts[i], max_tokens=budgets[i])
+                 for i in range(3)]
+        eng.step()
+        eng.begin_redeploy(params2).join()
+        late = [eng.submit(prompts[i], max_tokens=budgets[i])
+                for i in range(3, 7)]
+        eng.step()                           # installs epoch 1
+        banks[1] = (params2, eng.banks[eng.serving_epoch].cim)
+        for _ in range(8):                   # a late request takes a
+            if len(eng.scheduler.epochs_live()) == 2:   # freed slot
+                break
+            eng.step()
+        assert len(eng.scheduler.epochs_live()) == 2
+        out = eng.run()
+    assert eng.traces["decode"] == 1 and eng.pool.traces["merge"] == 1
+
+    @jax.jit
+    def forward(params, cim, tokens):
+        logits, _, _ = apply_model(params, cfg, ShardingCtx(),
+                                   tokens=tokens[None], cim=cim)
+        return jnp.argmax(logits[0], axis=-1)
+
+    for rids, epoch in ((first, 0), (late, 1)):
+        for rid in rids:
+            prompt, toks = prompts[rid], out[rid]
+            assert len(toks) == budgets[rid]
+            seq = np.zeros(64, np.int32)
+            seq[:len(prompt) + len(toks) - 1] = np.concatenate(
+                [prompt, toks[:-1]])
+            want = np.asarray(forward(*banks[epoch], jnp.asarray(seq)))
+            L = len(prompt)
+            assert list(want[L - 1:L - 1 + len(toks)]) == toks, rid
+
+
 def test_engine_rejects_oversized_prompts_and_bad_configs():
     cfg = _cfg()
     params = _params(cfg)
