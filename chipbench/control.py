@@ -100,7 +100,8 @@ def sweep(args, bench) -> None:
     for rate in args.rates:
         m = dict(mix, rate_per_s=rate)
         run = harness.Run(cell, cfg, m, {}, seed)
-        harness._drive(run, eng, args.seconds, cfg["vocab_size"], False)
+        harness._drive(run, eng, args.seconds, cfg["vocab_size"], False,
+                       ref_mod)
         open_at_close = sum(1 for rid in run.due
                             if len(run.recs[rid].tokens)
                             < run.recs[rid].max_tokens)

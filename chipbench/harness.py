@@ -11,7 +11,8 @@ per-layer ones in ``chipbench/layer_metrics/<name>.py``; each defines
 A mix's ``loop`` names its arrival process, ``chipbench/loops/<loop>.py``;
 a configuration's ``devices`` its device model,
 ``chipbench/devices/<model>.py``; its ``reference`` the plain
-reference, ``chipbench/references/<name>.py``.
+reference, ``chipbench/references/<name>.py``, which may bring the
+FLOP counts of its architecture (``flop_counter``).
 
 One run, one process:
 
@@ -187,11 +188,32 @@ class Window:
     clock: object = time.perf_counter
 
 
-def _drive(run: Run, eng, seconds: float, vocab: int, traced: bool) -> None:
+def flop_counter(cfg: dict, ref_mod):
+    """``(prefill(prompt_len), token(context))``: the useful FLOPs of a
+    prompt and of one sampled token.
+
+    The reference module's ``prefill_flops(cfg, prompt_len)`` and
+    ``token_flops(cfg, context, sampled)`` where it defines both, else
+    the dense layer's counts of ``chipbench.counts``.
+    """
+    own = [hasattr(ref_mod, f) for f in ("prefill_flops", "token_flops")]
+    if all(own):
+        return (lambda n: ref_mod.prefill_flops(cfg, n),
+                lambda n: ref_mod.token_flops(cfg, n, sampled=True))
+    if any(own):
+        raise SetupError(f"reference {cfg['reference']} defines one of "
+                         "prefill_flops and token_flops, not both")
+    shapes = counts.dense_shapes(cfg)
+    return (lambda n: counts.prefill_flops(shapes, n),
+            lambda n: counts.token_flops(shapes, n, sampled=True))
+
+
+def _drive(run: Run, eng, seconds: float, vocab: int, traced: bool,
+           ref_mod) -> None:
     """The measured window: the mix's loop drives ``submit`` and
     ``step``; the loop and its traffic are made before it opens."""
     mix = run.mix
-    shapes = counts.dense_shapes(run.cfg)
+    prefill_flops, token_flops = flop_counter(run.cfg, ref_mod)
     clock = time.perf_counter
 
     def on_token(rid, tok, done):
@@ -202,8 +224,7 @@ def _drive(run: Run, eng, seconds: float, vocab: int, traced: bool) -> None:
         L = len(r.prompt)
         r.tokens.append(tok)
         r.times.append(clock())
-        run.flops += (counts.prefill_flops(shapes, L) if n == 0 else
-                      counts.token_flops(shapes, L + n, sampled=True))
+        run.flops += prefill_flops(L) if n == 0 else token_flops(L + n)
 
     def submit(req, due):
         rid = eng.submit(req.prompt, max_tokens=req.max_tokens,
@@ -379,7 +400,7 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
             jax.profiler.start_trace(os.path.join(tmp, "profile"))
         run.setup_s = time.perf_counter() - t_start
         with compile_counter() as cc, _annotate(trace, "window"):
-            _drive(run, eng, seconds, vocab, trace)
+            _drive(run, eng, seconds, vocab, trace, ref_mod)
         run.compiles_in_window = cc["compiles"]
         if trace:
             jax.profiler.stop_trace()
@@ -420,8 +441,8 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
           f"{','.join(run.served)}; {run.tokens_in_window()} tokens in "
           f"{run.window_s:.3f} s; {len(run.due)} requests; "
           f"{run.compiles_in_window} compiles in the window; deploy "
-          f"{run.deploy_report.get('deploy_seconds', 0.0):.3f} s",
-          file=sys.stderr)
+          f"{run.deploy_report.get('deploy_seconds', 0.0):.3f} s; model "
+          f"keys mapped by {program.loader().__module__}", file=sys.stderr)
     for k, v in run.notes.items():
         print(f"chipbench: {k}: {v}", file=sys.stderr)
     result["checks"] = checks

@@ -5,61 +5,39 @@ the model configuration it is given, the serving engine it drives
 (``ContinuousEngine``: deploy, ``submit``, ``step``), and the report
 and kernel path that the engine states.
 
-A configuration file is mapped key by key.  A key that the program is
-not given, that only describes the configuration, or whose one served
-value is fixed, is named below; any other key is refused, so that no
-setting of a configuration file is dropped in silence.  The device
-model is a builder of its own, ``chipbench/devices/<model>.py``.
+A configuration file holds the benchmark's own keys (``BENCH_KEYS``:
+where the model comes from, how it was cut, its device model, its
+reference and limits) and the model's keys.  The model's keys are
+mapped to ``ModelConfig`` by the program's loader,
+``repro.configs.from_file.model_config_from_file``, which refuses any
+key it does not map; where the program has no such module, by the same
+function in ``chipbench/model_keys.py``.  The device model comes from
+a module of its own, ``chipbench/devices/<model>.py``.
 """
 from __future__ import annotations
 
-# Configuration key -> the ModelConfig field it sets.
-FIELDS = {
-    "family": "family", "block_pattern": "block_pattern",
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-    "sliding_window": "sliding_window", "mlp_type": "mlp_type",
-    "torch_dtype": "dtype",
-}
-# Keys the program has no option for: the one value it serves.
-FIXED = {"hidden_act": "silu", "tie_word_embeddings": False}
-# Keys that describe the configuration, or the benchmark's use of it.
-DESCRIPTIVE = {"name", "source", "paper", "model_type", "architecture",
-               "reference", "reduced", "assumed", "deployment", "served",
-               "max_position_embeddings", "limits"}
-# ``cim`` key -> the CimConfig field it sets; the rest describe.
-CIM_FIELDS = {"mapping": "mode", "eta": "eta", "rows": "rows",
-              "cols": "cols", "n_bits": "n_bits", "r": "r",
-              "r_on": "r_on", "r_off": "r_off"}
-CIM_DESCRIPTIVE = {"deployed", "digital"}
+import importlib.util
+
+# Keys of a configuration file that the benchmark reads, never the
+# program.
+BENCH_KEYS = ("source", "paper", "reference", "reduced", "assumed",
+              "deployment", "served", "limits", "devices")
+
+
+def loader():
+    """``model_config_from_file``: the program's own where it has one,
+    else ``chipbench.model_keys``'s, kept for a program without it."""
+    if importlib.util.find_spec("repro.configs.from_file") is not None:
+        from repro.configs.from_file import model_config_from_file
+    else:
+        from chipbench.model_keys import model_config_from_file
+    return model_config_from_file
 
 
 def model_config(cfg: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import CimConfig, ModelConfig
-
-    unknown = set(cfg) - set(FIELDS) - set(FIXED) - DESCRIPTIVE \
-        - {"cim", "devices"}
-    unknown |= {f"cim.{k}" for k in set(cfg["cim"]) - set(CIM_FIELDS)
-                - CIM_DESCRIPTIVE}
-    if unknown:
-        raise ValueError(f"{cfg['name']}: configuration keys the program "
-                         f"is not given: {sorted(unknown)}")
-    for key, value in FIXED.items():
-        if cfg.get(key, value) != value:
-            raise ValueError(f"{cfg['name']}: {key}={cfg[key]!r}; the "
-                             f"program serves {value!r} only")
-    kw = {field: cfg[key] for key, field in FIELDS.items() if key in cfg}
-    kw["block_pattern"] = tuple(kw["block_pattern"])
-    kw["head_dim"] = kw.get("head_dim") or 0
-    kw["sliding_window"] = int(kw.get("sliding_window") or 0)
-    cim = {field: cfg["cim"][key] for key, field in CIM_FIELDS.items()
-           if key in cfg["cim"]}
-    return ModelConfig(name=cfg["name"], **kw,
-                       cim=CimConfig(enabled=True, **cim))
+    """The program's ModelConfig for a configuration file: its model
+    keys, passed to ``loader()``."""
+    return loader()({k: v for k, v in cfg.items() if k not in BENCH_KEYS})
 
 
 def make_engine(cfg: dict, mix: dict, params, plan_root: str):

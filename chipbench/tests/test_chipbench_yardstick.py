@@ -138,6 +138,78 @@ def test_token_and_prefill_flops_by_hand():
     assert counts.prefill_flops(s_w, L) == by_token
 
 
+def test_flop_counter_without_reference_counts_is_the_dense_layer():
+    """phi3's reference brings no counts: the window counts what
+    ``counts.py`` counts for it, as written out here."""
+    cfg = harness.load_data("configs", "phi3-mini-3.8b.ideal")
+    ref = harness.load_module("references", cfg["reference"])
+    assert not hasattr(ref, "token_flops")
+    assert not hasattr(ref, "prefill_flops")
+    prefill, token = harness.flop_counter(cfg, ref)
+    assert token(100) == 880_164_864
+    assert token(3000) == 951_939_072               # window of 2047
+    assert prefill(7) == 4_954_374_144
+    assert prefill(1536) == 1_087_388_909_568
+
+
+def _own_counts():
+    import types
+
+    return types.SimpleNamespace(
+        prefill_flops=lambda cfg, n: 1000 * n + cfg["vocab_size"],
+        token_flops=lambda cfg, n, sampled: 10 * n + (7 if sampled else 0))
+
+
+def test_flop_counter_takes_the_reference_counts():
+    import types
+
+    cfg = harness.load_data("configs", "phi3-mini-3.8b.ideal")
+    prefill, token = harness.flop_counter(cfg, _own_counts())
+    assert prefill(5) == 5000 + 32064
+    assert token(40) == 407
+    half = types.SimpleNamespace(token_flops=_own_counts().token_flops)
+    with pytest.raises(harness.SetupError, match="prefill_flops"):
+        harness.flop_counter(cfg, half)
+
+
+class _Engine:
+    """Answers each request with one token a step, up to its length."""
+
+    def __init__(self):
+        self.reqs, self.scheduler = {}, self
+
+    @property
+    def queue_depth(self):
+        return sum(1 for r in self.reqs.values() if r[2] < r[1])
+
+    pending = queue_depth
+
+    def submit(self, prompt, max_tokens, temperature, on_token):
+        self.reqs[len(self.reqs)] = [on_token, max_tokens, 0]
+        return len(self.reqs) - 1
+
+    def step(self):
+        for rid, r in self.reqs.items():
+            if r[2] < r[1]:
+                r[2] += 1
+                r[0](rid, 5, r[2] == r[1])
+
+
+def test_window_counts_flops_by_the_reference():
+    """``_drive`` adds each token's FLOPs as the reference counts them:
+    the prompt's at the first token, one sampled token's after it."""
+    cfg = harness.load_data("configs", "phi3-mini-3.8b.ideal")
+    mix = dict(harness.load_data("traffic", "batch"), capacity=4)
+    own = _own_counts()
+    run = harness.Run({}, cfg, mix, {}, BIG_SEED)
+    harness._drive(run, _Engine(), 0.05, cfg["vocab_size"], False, own)
+    want = sum(own.prefill_flops(cfg, len(r.prompt)) if n == 0 else
+               own.token_flops(cfg, len(r.prompt) + n, True)
+               for r in run.recs.values() for n in range(len(r.tokens)))
+    assert run.tokens_in_window() > 0
+    assert run.flops == want
+
+
 # ------------------------------------------------------------ discovery
 
 
@@ -196,23 +268,100 @@ def test_benchmark_file_shape(bench):
     del layers
 
 
-@pytest.mark.parametrize("change", [
+UNMAPPED = [
     {"rope_scaling": {"type": "longrope"}},
     {"cim": {"sigma_read": 0.01}},
     {"hidden_act": "gelu_new"},
     {"tie_word_embeddings": True},
+]
+
+
+@pytest.mark.parametrize("entry, change", [
+    *(pytest.param("adapter", c, id=f"change{i}")
+      for i, c in enumerate(UNMAPPED)),
+    *(pytest.param("loader", c, id=f"loader-change{i}")
+      for i, c in enumerate(
+          UNMAPPED + [{"source": "a key of the benchmark's own"}])),
 ])
-def test_model_config_refuses_what_it_cannot_map(change):
-    """No key of a configuration file is dropped in silence."""
+def test_model_config_refuses_what_it_cannot_map(entry, change):
+    """No key of a configuration file is dropped in silence: the adapter
+    refuses it, and so does the loader given the model keys alone, and
+    each names the configuration."""
     from chipbench import program
 
     cfg = harness.load_data("configs", "phi3-mini-3.8b.ideal")
-    program.model_config(cfg)
+    if entry == "adapter":
+        load = program.model_config
+    else:
+        load = program.loader()
+        cfg = {k: v for k, v in cfg.items() if k not in program.BENCH_KEYS}
+    load(cfg)
     for key, value in change.items():
         cfg[key] = ({**cfg[key], **value} if key in cfg
                     and isinstance(value, dict) else value)
     with pytest.raises(ValueError, match="phi3-mini"):
+        load(cfg)
+
+
+def test_phi3_model_config_field_for_field():
+    """The phi3 file gives the ModelConfig it gave when the benchmark
+    mapped its keys itself: every field as written out here."""
+    import dataclasses
+
+    from chipbench import program
+    from repro.configs.base import CimConfig, ModelConfig
+
+    got = program.model_config(harness.load_data("configs",
+                                                 "phi3-mini-3.8b.ideal"))
+    cim = CimConfig(enabled=True, mode="mdm", eta=0.002, rows=64, cols=64,
+                    n_bits=8, r=2.5, r_on=300000.0, r_off=3000000.0)
+    want = ModelConfig(
+        name="phi3-mini-3.8b.ideal", family="dense", n_layers=3,
+        d_model=3072, n_heads=32, n_kv_heads=32, d_ff=8192,
+        vocab_size=32064, head_dim=96, block_pattern=("attn",),
+        rope_theta=10000.0, qkv_bias=False, sliding_window=2047,
+        attn_chunk=512, attn_impl="jax", n_experts=0,
+        n_experts_per_token=0, n_shared_experts=0, moe_d_ff=0,
+        capacity_factor=1.25, moe_dispatch="global",
+        gqa_broadcast="repeat", attn_remat_chunk=False,
+        cache_update="scatter", attn_fallback_shard="head_dim",
+        ssm_state=16, ssm_conv=4, ssm_expand=1, ssm_chunk=64,
+        mlstm_chunk=128, slstm_tp="shard", frontend="", mlp_type="swiglu",
+        norm_eps=1e-05, dtype="bfloat16", remat="full",
+        logical_rules="default", loss_chunk=0, cim=cim)
+    assert [f.name for f in dataclasses.fields(got)] == \
+        [f.name for f in dataclasses.fields(want)]
+    for f in dataclasses.fields(want):
+        assert getattr(got, f.name) == getattr(want, f.name), f.name
+        assert type(getattr(got, f.name)) is type(getattr(want, f.name)), \
+            f.name
+
+
+def test_a_key_on_the_program_side_reaches_model_config(monkeypatch):
+    """A key that only the program's loader maps reaches ModelConfig
+    through the adapter, with no file of the benchmark changed: here the
+    program's ``repro.configs.from_file`` is the benchmark's loader with
+    one more table entry."""
+    import importlib.util
+
+    from chipbench import model_keys, program
+
+    key = "experts_of_a_later_model"
+    cfg = dict(harness.load_data("configs", "phi3-mini-3.8b.ideal"),
+               **{key: 8})
+    with pytest.raises(ValueError, match=key):
         program.model_config(cfg)
+    name = "repro.configs.from_file"
+    spec = importlib.util.spec_from_file_location(name, model_keys.__file__)
+    theirs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(theirs)
+    monkeypatch.setitem(theirs.FIELDS, key, "n_experts")
+    monkeypatch.setitem(sys.modules, name, theirs)
+    assert program.loader() is theirs.model_config_from_file
+    assert program.model_config(cfg).n_experts == 8
+    assert key not in model_keys.FIELDS
+    with pytest.raises(ValueError, match="not_a_key"):
+        program.model_config(dict(cfg, not_a_key=1))
 
 
 def test_ideal_devices_take_no_settings():
